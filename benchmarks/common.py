@@ -1,4 +1,9 @@
-"""Benchmark helpers: timing + subprocess runner for multi-device benches."""
+"""Benchmark helpers: timing + subprocess runner for multi-device benches.
+
+``run_with_devices`` is a CPU simulation of a multi-device host: the child
+runs on ``n_devices`` forced host devices with ``JAX_PLATFORMS=cpu`` (the
+parent may already hold an accelerator, which a child could not open).
+Rows computed in it say ``platform=cpu``."""
 from __future__ import annotations
 
 import os
@@ -6,6 +11,8 @@ import subprocess
 import sys
 import textwrap
 import time
+
+import jax
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -16,11 +23,7 @@ def time_us(fn, *args, warmup: int = 2, iters: int = 5) -> float:
     t0 = time.perf_counter()
     for _ in range(iters):
         out = fn(*args)
-    try:
-        import jax
-        jax.block_until_ready(out)
-    except Exception:
-        pass
+    jax.block_until_ready(out)
     return (time.perf_counter() - t0) / iters * 1e6
 
 
@@ -28,6 +31,7 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 1200
                      ) -> str:
     env = dict(os.environ)
     env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
+    env["JAX_PLATFORMS"] = "cpu"
     env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
     res = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                          env=env, capture_output=True, text=True,

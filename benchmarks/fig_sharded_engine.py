@@ -9,10 +9,12 @@ GRPO group fork and an in-flight weight relay. The payoff reported is
 the memory shape: per-device KV bytes shrink by the model-axis size
 while the streams don't move.
 
-The measurement needs 8 devices, so it runs in a subprocess with
+The measurement needs 8 devices, so it is a CPU simulation: a subprocess
+with ``JAX_PLATFORMS=cpu`` and
 ``XLA_FLAGS=--xla_force_host_platform_device_count=8`` (same pattern as
 tests/test_sharded_engine.py) — the parent benchmark process keeps
-whatever device topology it started with.
+whatever device topology it started with, and its rows say
+``platform=cpu``.
 """
 from __future__ import annotations
 
@@ -90,11 +92,11 @@ def main():
         ("sharded_stream_parity", 0.0,
          f"byte-identical tokens+logprobs+versions on [{shape8}] vs "
          f"[{shape1}] ({toks} tokens incl. group fork + in-flight "
-         f"weight relay)"),
+         f"weight relay) platform=cpu"),
         ("sharded_kv_bytes_per_shard", 0.0,
          f"{shard_bytes}B per device shard vs {pool_bytes}B full pool "
          f"(KV heads split over the model axis; expert stacks over "
-         f"expert)"),
+         f"expert) platform=cpu"),
     ]
 
 

@@ -48,6 +48,9 @@ MODULES = [
 
 def main() -> None:
     import importlib
+
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     failures = []
     print("name,us_per_call,derived")
     for tag, modname in MODULES:

@@ -68,11 +68,11 @@ for scheme in ('round_robin', 'all_to_all'):
         scheme, count, byts = line.split(",")
         vals[scheme] = (int(count), int(byts))
         rows.append((f"muon_{scheme}_collectives", float(count),
-                     f"{int(byts) / 1e6:.1f}MB wire"))
+                     f"{int(byts) / 1e6:.1f}MB wire platform=cpu"))
     rr, a2a = vals["round_robin"], vals["all_to_all"]
     rows.append(("muon_a2a_vs_rr_bytes_ratio", 0.0,
                  f"{rr[1] / max(a2a[1], 1):.1f}x less data, "
-                 f"{rr[0] / max(a2a[0], 1):.1f}x fewer ops"))
+                 f"{rr[0] / max(a2a[0], 1):.1f}x fewer ops platform=cpu"))
     return rows
 
 

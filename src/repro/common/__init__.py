@@ -1,1 +1,1 @@
-from . import compat, pytree  # noqa: F401
+from . import pytree  # noqa: F401

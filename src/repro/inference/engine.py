@@ -192,6 +192,7 @@ fork (host-side row broadcast + eager scatter).
 from __future__ import annotations
 
 import contextlib
+import functools
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass, field
@@ -739,11 +740,25 @@ class InferenceEngine:
         else:
             self.allocator = None
         if self.paged:
-            self.state = init_paged_state(cfg, num_slots, num_kv_blocks, bs,
-                                          self._blocks_per_row, cache_dtype)
+            make_state = functools.partial(
+                init_paged_state, cfg, num_slots, num_kv_blocks, bs,
+                self._blocks_per_row, cache_dtype)
         else:
-            self.state = init_decode_state(cfg, num_slots, max_seq,
-                                           cache_dtype)
+            make_state = functools.partial(init_decode_state, cfg, num_slots,
+                                           max_seq, cache_dtype)
+        # a meshed engine creates its cache state directly in its layout
+        # (one jitted init) — never whole on one device first
+        self._state_shardings = None
+        if mesh is None:
+            self.state = make_state()
+        else:
+            specs = decode_state_specs(cfg, mesh, batch=num_slots,
+                                       paged=self.paged, shard_heads=True)
+            self._state_shardings = {
+                k: NamedSharding(mesh, specs[k])
+                for k in jax.eval_shape(make_state)}
+            self.state = jax.jit(make_state,
+                                 out_shardings=self._state_shardings)()
         # prefix-cache per-slot publication bookkeeping: the token ids
         # written at cache positions [0, _slot_len), the chain nodes
         # already published for the slot's leading full blocks, and the
@@ -784,20 +799,13 @@ class InferenceEngine:
         self._max_new = jnp.ones((num_slots,), jnp.int32)
         self._rng = jax.random.PRNGKey(seed)
 
-        # mesh placement: lay out params, cache state and slot bookkeeping
-        # across the engine's shard set. Donation through the jitted paths
-        # requires stable layouts, so the impls re-constrain their state
-        # outputs to these same shardings (_constrain_state).
-        self._state_shardings = None
+        # mesh placement: lay out params and slot bookkeeping across the
+        # engine's shard set (the cache state already is). Donation through
+        # the jitted paths requires stable layouts, so the impls re-constrain
+        # their state outputs to these same shardings (_constrain_state).
         self._param_shardings = None
         self._slot_sharding = None
         if mesh is not None:
-            specs = decode_state_specs(cfg, mesh, batch=num_slots,
-                                       paged=self.paged, shard_heads=True)
-            self._state_shardings = {k: NamedSharding(mesh, specs[k])
-                                     for k in self.state}
-            self.state = {k: jax.device_put(v, self._state_shardings[k])
-                          for k, v in self.state.items()}
             self._param_shardings = jax.tree_util.tree_map(
                 lambda s: NamedSharding(mesh, s),
                 serve_param_specs(params, mesh, cfg))

@@ -1,8 +1,9 @@
 """Jit'd public entrypoints for the Pallas kernels.
 
-TPU is the *target*; this container is CPU-only, so the kernels default to
-``interpret=True`` off-TPU (the kernel body runs in Python for correctness)
-and compile natively when a TPU backend is present. Model code calls these
+TPU is the *target*: on a TPU backend every kernel compiles natively
+(Mosaic, a ``tpu_custom_call`` in the compiled program) and never runs in
+interpret mode. On any other backend (CPU tests) the kernel body runs in
+the Pallas interpreter, for correctness only. Model code calls these
 only under ``ParallelConfig.use_pallas``; the XLA reference paths in
 ``repro.models`` are used otherwise, so dry-run lowering never depends on
 Pallas.

@@ -35,6 +35,24 @@ def flash_attention_ref(q, k, v, *, causal=True, window=0):
     return out.astype(q.dtype)
 
 
+def paged_attention_ref(q, k_pool, v_pool, block_tables, pos):
+    """One-token decode attention through a block table, done the naive
+    way: gather each row's blocks into a dense cache, repeat the KV heads,
+    softmax over the keys ``<= pos``. q: [B,1,Hq,hd]; pools:
+    [num_blocks, bs, Hkv, hd]; block_tables: [B, max_blocks]; pos: [B]."""
+    B, _, Hq, hd = q.shape
+    Hkv = k_pool.shape[2]
+    k = k_pool[block_tables].reshape(B, -1, Hkv, hd)
+    v = v_pool[block_tables].reshape(B, -1, Hkv, hd)
+    k = jnp.repeat(k, Hq // Hkv, axis=2).astype(jnp.float32)
+    v = jnp.repeat(v, Hq // Hkv, axis=2).astype(jnp.float32)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q.astype(jnp.float32), k) * hd ** -0.5
+    valid = jnp.arange(k.shape[1])[None, :] <= pos[:, None]
+    s = jnp.where(valid[:, None, None, :], s, NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", p, v).astype(q.dtype)
+
+
 def grouped_matmul_ref(x, w, group_sizes):
     """x: [E,C,d]; w: [E,d,f]; rows >= group_sizes[e] are zeroed."""
     y = jnp.einsum("ecd,edf->ecf", x.astype(jnp.float32),

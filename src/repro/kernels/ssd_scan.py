@@ -23,10 +23,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, h0_ref,   # inputs
-                y_ref, hT_ref,                                  # outputs
-                h_ref,                                          # VMEM scratch
-                *, chunk, num_chunks, seq_len):
+def _ssd_kernel(x_ref, dt_ref, ac_ref, ar_ref, b_ref, c_ref, h0_ref,  # in
+                y_ref, hT_ref,                                      # out
+                h_ref,                                              # VMEM
+                *, chunk, num_chunks):
     ic = pl.program_id(1)
 
     @pl.when(ic == 0)
@@ -34,27 +34,21 @@ def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, h0_ref,   # inputs
         h_ref[...] = h0_ref[0].astype(jnp.float32)
 
     x = x_ref[0].astype(jnp.float32)          # [L, hd]
-    dt = dt_ref[0].astype(jnp.float32)        # [L]
-    da = da_ref[0].astype(jnp.float32)        # [L] (= dt * A, negative)
+    dt = dt_ref[0]                            # [L, 1]
+    a_col = ac_ref[0]                         # [L, 1] in-chunk cumsum of dt*A
+    a_row = ar_ref[0]                         # [1, L] the same, as a row
     Bc = b_ref[0].astype(jnp.float32)         # [L, n]
     Cc = c_ref[0].astype(jnp.float32)         # [L, n]
-
-    # mask out padded tail positions (beyond seq_len)
-    idx = ic * chunk + jax.lax.broadcasted_iota(jnp.int32, (chunk,), 0)
-    valid = idx < seq_len
-    dt = jnp.where(valid, dt, 0.0)
-    da = jnp.where(valid, da, 0.0)
-
-    a_cum = jnp.cumsum(da)                    # [L]
 
     # intra-chunk quadratic term: scores[i,j] = (C_i·B_j)·exp(a_i-a_j)·1[i>=j]
     scores = jax.lax.dot_general(Cc, Bc, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)  # [L,L]
     i_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     j_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
-    decay = jnp.exp(a_cum[:, None] - a_cum[None, :])
-    scores = jnp.where(i_idx >= j_idx, scores * decay, 0.0)
-    xdt = x * dt[:, None]                     # [L, hd]
+    causal = i_idx >= j_idx
+    decay = jnp.exp(jnp.where(causal, a_col - a_row, 0.0))
+    scores = jnp.where(causal, scores * decay, 0.0)
+    xdt = x * dt                              # [L, hd]
     y_intra = jax.lax.dot_general(scores, xdt, (((1,), (0,)), ((), ())),
                                   preferred_element_type=jnp.float32)
 
@@ -62,13 +56,13 @@ def _ssd_kernel(x_ref, dt_ref, da_ref, b_ref, c_ref, h0_ref,   # inputs
     h = h_ref[...]
     Ch = jax.lax.dot_general(Cc, h, (((1,), (1,)), ((), ())),
                              preferred_element_type=jnp.float32)  # [L, hd]
-    y_inter = jnp.exp(a_cum)[:, None] * Ch
-    y_ref[0] = (y_intra + y_inter).astype(y_ref.dtype)
+    y_ref[0] = (y_intra + jnp.exp(a_col) * Ch).astype(y_ref.dtype)
 
     # state update: h' = exp(a_end)·h + sum_j exp(a_end - a_j)·dt_j·x_j⊗B_j
-    a_end = a_cum[chunk - 1]
-    w = jnp.exp(a_end - a_cum) * dt           # [L]
-    xw = x * w[:, None]                       # [L, hd]
+    # a_end as a true scalar (a [1, 1] slice cannot broadcast to [hd, n])
+    last = jax.lax.broadcasted_iota(jnp.int32, a_row.shape, 1) == chunk - 1
+    a_end = jnp.sum(jnp.where(last, a_row, 0.0))
+    xw = x * (jnp.exp(a_end - a_col) * dt)    # [L, hd]
     outer = jax.lax.dot_general(xw, Bc, (((0,), (0,)), ((), ())),
                                 preferred_element_type=jnp.float32)  # [hd, n]
     h_ref[...] = jnp.exp(a_end) * h + outer
@@ -101,21 +95,29 @@ def ssd_scan(xh, dt, dA_log, Bh, Ch, h0, *, chunk=128, interpret=True):
         return a
 
     xf = to_bh(xh, (hd,))
-    dtf = to_bh(dt, ())
-    daf = to_bh(dA_log, ())
     Bf = to_bh(Bh, (n,))
     Cf = to_bh(Ch, (n,))
     h0f = h0.reshape(B * nh, hd, n)
+    # dt and the in-chunk cumsum of dA_log are per-position scalars. They
+    # go in as a column [BH, Sp, 1] (broadcast against [L, hd] rows) and
+    # the cumsum also as a row [BH, 1, Sp] (the j side of the decay
+    # matrix): both block shapes keep the (8, 128) tiling, which a 2-D
+    # [BH, Sp] array blocked by (1, chunk) cannot. Zero padding of the tail
+    # makes padded positions pass the state through unchanged.
+    dtf = to_bh(dt, ()).astype(jnp.float32)
+    acum = jnp.cumsum(to_bh(dA_log, ()).astype(jnp.float32)
+                      .reshape(B * nh, nc, chunk), axis=-1).reshape(
+                          B * nh, Sp)
 
-    kernel = functools.partial(_ssd_kernel, chunk=chunk, num_chunks=nc,
-                               seq_len=S)
+    kernel = functools.partial(_ssd_kernel, chunk=chunk, num_chunks=nc)
     y, hT = pl.pallas_call(
         kernel,
         grid=(B * nh, nc),
         in_specs=[
             pl.BlockSpec((1, chunk, hd), lambda bh, ic: (bh, ic, 0)),
-            pl.BlockSpec((1, chunk), lambda bh, ic: (bh, ic)),
-            pl.BlockSpec((1, chunk), lambda bh, ic: (bh, ic)),
+            pl.BlockSpec((1, chunk, 1), lambda bh, ic: (bh, ic, 0)),
+            pl.BlockSpec((1, chunk, 1), lambda bh, ic: (bh, ic, 0)),
+            pl.BlockSpec((1, 1, chunk), lambda bh, ic: (bh, 0, ic)),
             pl.BlockSpec((1, chunk, n), lambda bh, ic: (bh, ic, 0)),
             pl.BlockSpec((1, chunk, n), lambda bh, ic: (bh, ic, 0)),
             pl.BlockSpec((1, hd, n), lambda bh, ic: (bh, 0, 0)),
@@ -130,7 +132,7 @@ def ssd_scan(xh, dt, dA_log, Bh, Ch, h0, *, chunk=128, interpret=True):
         ],
         scratch_shapes=[pltpu.VMEM((hd, n), jnp.float32)],
         interpret=interpret,
-    )(xf, dtf, daf, Bf, Cf, h0f)
+    )(xf, dtf[..., None], acum[..., None], acum[:, None], Bf, Cf, h0f)
 
     y = y[:, :S].reshape(B, nh, S, hd).transpose(0, 2, 1, 3)
     hT = hT.reshape(B, nh, hd, n)

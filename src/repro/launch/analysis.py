@@ -251,8 +251,6 @@ def analyze_compiled(compiled, meta: dict, *, n_chips: int) -> dict:
     """
     from repro.launch.hlo_parse import collective_wire_bytes
     cost = compiled.cost_analysis() or {}
-    if isinstance(cost, (list, tuple)):   # jax<=0.4.x: one dict per program
-        cost = cost[0] if cost else {}
     flops_ca = float(cost.get("flops", 0.0))          # per-device, body-once
     bytes_ca = float(cost.get("bytes accessed", 0.0))
     hlo_text = compiled.as_text()

@@ -6,17 +6,10 @@ state (the dry-run must set XLA_FLAGS before first jax init).
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-try:  # jax >= 0.5 makes axis types explicit; 0.4.x meshes are Auto already
-    from jax.sharding import AxisType
-except ImportError:  # pragma: no cover - depends on installed jax
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def _axis_kwargs(n: int) -> dict:
-    if AxisType is None:
-        return {}
     return {"axis_types": (AxisType.Auto,) * n}
 
 

@@ -20,11 +20,73 @@ from __future__ import annotations
 
 import argparse
 import dataclasses
+import functools
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding
+
+
+def is_reduced(arch: str) -> bool:
+    return arch.partition(":")[2] == "reduced"
+
+
+def serving_config(arch: str):
+    """The served model config. ``:reduced`` archs are test-sized models
+    over the byte tokenizer's vocabulary; every other arch keeps its
+    published vocabulary."""
+    from repro.configs import get_config
+    from repro.data import TOKENIZER
+    cfg = get_config(arch)
+    if is_reduced(arch):
+        cfg = dataclasses.replace(cfg, vocab_size=TOKENIZER.vocab_size)
+    return cfg
+
+
+def init_serving_params(cfg, seed: int, *, mesh=None, dtype=None):
+    """Random params, made on the device by one jitted init in ``dtype``
+    (default: the config's) — never a float32 copy, never a host round
+    trip. With a ``mesh`` every leaf is created directly in the engine's
+    serving layout (``serve_param_specs``), so a model larger than one
+    device never has to fit on one."""
+    from repro.models import init_params
+    from repro.sharding.rules import serve_param_specs
+    init = functools.partial(init_params, cfg=cfg, dtype=dtype)
+    key = jax.random.PRNGKey(seed)
+    if mesh is None:
+        return jax.jit(init)(key)
+    shapes = jax.eval_shape(init, key)
+    shardings = jax.tree_util.tree_map(
+        lambda s: NamedSharding(mesh, s), serve_param_specs(shapes, mesh, cfg))
+    return jax.jit(init, out_shardings=shardings)(key)
+
+
+def build_pool(arch: str, *, slots: int, max_seq: int, seed: int = 0,
+               engines: int = 1, mesh=None, **engine_kw):
+    """The serving stack for ``arch``: ``engines`` independent engines
+    behind one ``InferencePool`` — or, with ``mesh=(dp, tp[, ep])``, dp
+    engines each spanning its own tp x ep device mesh. Params are bf16 at
+    published widths, except ``:reduced`` archs, which serve float32.
+    Returns (cfg, pool)."""
+    from repro.configs.base import ParallelConfig
+    from repro.inference import InferenceEngine, InferencePool
+    from repro.launch.mesh import make_engine_meshes
+
+    cfg = serving_config(arch)
+    dtype = jnp.float32 if is_reduced(arch) else None
+    pcfg = ParallelConfig(remat="none", loss_chunk=0)
+    if mesh is None:      # unsharded engines share one copy of the params
+        placed = [(init_serving_params(cfg, seed, dtype=dtype), None)] \
+            * engines
+    else:
+        placed = [(init_serving_params(cfg, seed, mesh=m, dtype=dtype), m)
+                  for m in make_engine_meshes(*mesh)]
+    built = [InferenceEngine(params, cfg, num_slots=slots, max_seq=max_seq,
+                             pcfg=pcfg, seed=i, mesh=m, **engine_kw)
+             for i, (params, m) in enumerate(placed)]
+    return cfg, InferencePool(built)
 
 
 def main():
@@ -77,50 +139,27 @@ def main():
     else:
         prefill_budget = int(args.prefill_budget)
 
-    from repro.configs import get_config
-    from repro.configs.base import ParallelConfig
+    from repro.common.compile_cache import use_compile_cache
     from repro.data import TOKENIZER
-    from repro.inference import InferenceEngine, InferencePool, Request
-    from repro.launch.mesh import make_engine_meshes
-    from repro.models import init_params
-
-    cfg = dataclasses.replace(get_config(args.arch),
-                              vocab_size=TOKENIZER.vocab_size)
-    pcfg = ParallelConfig(remat="none", loss_chunk=0)
-    params = init_params(jax.random.PRNGKey(args.seed), cfg,
-                         dtype=jnp.float32)
+    use_compile_cache()
+    mesh = None
     if args.mesh is not None:
-        factors = [int(f) for f in args.mesh.split(",")]
-        if not 2 <= len(factors) <= 3:
+        mesh = tuple(int(f) for f in args.mesh.split(","))
+        if not 2 <= len(mesh) <= 3:
             raise SystemExit("--mesh expects dp,tp or dp,tp,ep")
-        dp, tp = factors[0], factors[1]
-        ep = factors[2] if len(factors) == 3 else 1
-        meshes = make_engine_meshes(dp, tp, ep)
-        engines = [InferenceEngine(params, cfg, num_slots=args.slots,
-                                   max_seq=args.max_seq, pcfg=pcfg,
-                                   seed=i, spec_draft=args.spec_draft,
-                                   spec_ngram=args.spec_ngram,
-                                   chunk_prefill=args.chunk_prefill,
-                                   prefill_token_budget=prefill_budget,
-                                   promote_after=args.promote_after,
-                                   promote_after_ms=args.promote_after_ms,
-                                   prefix_cache=args.prefix_cache, mesh=m)
-                   for i, m in enumerate(meshes)]
-        print(f"mesh serving: {dp} engine shard(s) x "
-              f"{tp * ep} device(s) each "
-              f"({len(jax.devices()) - dp * tp * ep} idle)")
-    else:
-        engines = [InferenceEngine(params, cfg, num_slots=args.slots,
-                                   max_seq=args.max_seq, pcfg=pcfg, seed=i,
-                                   spec_draft=args.spec_draft,
-                                   spec_ngram=args.spec_ngram,
-                                   chunk_prefill=args.chunk_prefill,
-                                   prefill_token_budget=prefill_budget,
-                                   promote_after=args.promote_after,
-                                   promote_after_ms=args.promote_after_ms,
-                                   prefix_cache=args.prefix_cache)
-                   for i in range(args.engines)]
-    pool = InferencePool(engines)
+    _, pool = build_pool(args.arch, slots=args.slots, max_seq=args.max_seq,
+                         seed=args.seed, engines=args.engines, mesh=mesh,
+                         spec_draft=args.spec_draft,
+                         spec_ngram=args.spec_ngram,
+                         chunk_prefill=args.chunk_prefill,
+                         prefill_token_budget=prefill_budget,
+                         promote_after=args.promote_after,
+                         promote_after_ms=args.promote_after_ms,
+                         prefix_cache=args.prefix_cache)
+    if mesh is not None:
+        per = int(np.prod(mesh[1:]))
+        print(f"mesh serving: {mesh[0]} engine shard(s) x {per} device(s) "
+              f"each ({len(jax.devices()) - mesh[0] * per} idle)")
 
     rng = np.random.RandomState(args.seed)
     t0 = time.time()
@@ -200,8 +239,11 @@ def main():
     print(f"mean slot occupancy: {np.mean(occ):.2f}/{args.slots} "
           f"(continuous batching keeps slots saturated)")
     for r in done[:3]:
+        # the byte tokenizer can only render the reduced archs' vocabulary
+        out = (TOKENIZER.decode(r.completion) if is_reduced(args.arch)
+               else r.completion[:8])
         print(f"  {r.problem_id}: {len(r.completion)} tokens "
-              f"({r.finish_reason}) -> {TOKENIZER.decode(r.completion)!r}")
+              f"({r.finish_reason}) -> {out!r}")
 
 
 if __name__ == "__main__":

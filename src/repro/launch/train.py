@@ -126,6 +126,8 @@ def main():
     p.add_argument("--problems", type=int, default=32)
     p.add_argument("--max-new-tokens", type=int, default=8)
     args = p.parse_args()
+    from repro.common.compile_cache import use_compile_cache
+    use_compile_cache()
     if args.mode == "sft":
         run_sft(args)
     else:

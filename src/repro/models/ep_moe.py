@@ -25,10 +25,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-
-from repro.common.compat import axis_size
 
 
 def _pack_by_key(keys, values_list, num_buckets: int, cap: int, fill=0.0):
@@ -68,7 +65,7 @@ def _ep_body(x, weights, experts, router_unused, wg, wu, wd, *,
     """
     T, d = x.shape
     K = experts.shape[1]
-    n_ranks = axis_size(axis)
+    n_ranks = jax.lax.axis_size(axis)
     rank = jax.lax.axis_index(axis)
     E_loc = E // n_ranks
 
@@ -151,11 +148,11 @@ def ep_moe_dispatch(params, x, weights, experts, cfg, mesh: Mesh, *,
             cap_exp=cap_exp)
         return y.reshape(Bl, Sl, dd), dropped
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body, mesh=mesh,
         in_specs=(x_spec, k_spec, k_spec, w_spec, w_spec, w_spec),
         out_specs=(x_spec, P()),
-        check_rep=False)
+        check_vma=False)
     # storage may shard expert features over "data" (full ZeRO-3 for the
     # optimizer state); gather that axis at use so each model-rank holds its
     # whole local experts for the shard_map GEMMs.

@@ -74,8 +74,11 @@ def _encoder_layer_init(key, cfg: ModelConfig, dtype):
 
 
 def _stack_init(key, n, init_fn):
-    keys = jax.random.split(key, n)
-    return jax.vmap(init_fn)(keys)
+    # one layer at a time (same values as a vmap over the keys): a jitted
+    # init then holds one layer's float32 draws in temporaries, not all of
+    # them — what lets a full-depth model be created on the device(s) it
+    # is served from
+    return jax.lax.map(init_fn, jax.random.split(key, n))
 
 
 def init_params(key, cfg: ModelConfig, dtype=None):
@@ -742,11 +745,10 @@ def sample_logits(key, logits, temps):
     mesh = current_serve_mesh()
     if mesh is None:
         return _sample_logits_core(key, logits, temps)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    fn = shard_map(_sample_logits_core, mesh=mesh,
-                   in_specs=(P(), P(), P()), out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(_sample_logits_core, mesh=mesh,
+                       in_specs=(P(), P(), P()), out_specs=(P(), P()),
+                       check_vma=False)
     return fn(key, logits, temps)
 
 
@@ -1066,11 +1068,10 @@ def sample_logits_block(key, logits, temps):
     mesh = current_serve_mesh()
     if mesh is None:
         return _sample_logits_block_core(key, logits, temps)
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
-    fn = shard_map(_sample_logits_block_core, mesh=mesh,
-                   in_specs=(P(), P(), P()), out_specs=(P(), P()),
-                   check_rep=False)
+    fn = jax.shard_map(_sample_logits_block_core, mesh=mesh,
+                       in_specs=(P(), P(), P()), out_specs=(P(), P()),
+                       check_vma=False)
     return fn(key, logits, temps)
 
 

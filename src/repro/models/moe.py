@@ -202,7 +202,6 @@ def _moe_serve_apply(params, x, cfg, cap, mesh):
     whatever axes it likes and prefill logits drift ~1e-6 — enough to flip
     sampled tokens and break the engine's parity gate.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
     m = cfg.moe
     B, S, d = x.shape
@@ -219,9 +218,9 @@ def _moe_serve_apply(params, x, cfg, cap, mesh):
             x, weights, experts)
         return xe, info, probs
 
-    xe, info, probs = shard_map(
+    xe, info, probs = jax.shard_map(
         dispatch, mesh=mesh, in_specs=(rep, rep),
-        out_specs=(rep, (rep,) * 5, rep), check_rep=False)(
+        out_specs=(rep, (rep,) * 5, rep), check_vma=False)(
         x, params["router"])
 
     e_axis = _serve_expert_axis(mesh, E)
@@ -233,9 +232,9 @@ def _moe_serve_apply(params, x, cfg, cap, mesh):
         up = jnp.einsum("becd,edf->becf", xe, wu)
         return jnp.einsum("becf,efd->becd", gate * up, wd)
 
-    ye = shard_map(expert_mlp, mesh=mesh,
-                   in_specs=(xspec, wspec, wspec, wspec), out_specs=xspec,
-                   check_rep=False)(
+    ye = jax.shard_map(expert_mlp, mesh=mesh,
+                       in_specs=(xspec, wspec, wspec, wspec), out_specs=xspec,
+                       check_vma=False)(
         xe, params["w_gate"], params["w_up"], params["w_down"])
 
     shared = m.num_shared_experts
@@ -265,10 +264,10 @@ def _moe_serve_apply(params, x, cfg, cap, mesh):
         params["shared"]["w_gate"], params["shared"]["w_up"],
         params["shared"]["w_down"], params["shared_gate"])
     n_in = 4 + len(sh_args)
-    y, aux_loss, max_violation, dropped = shard_map(
+    y, aux_loss, max_violation, dropped = jax.shard_map(
         combine, mesh=mesh,
         in_specs=(rep, (rep,) * 5) + (rep,) * (n_in - 2),
-        out_specs=(rep, rep, rep, rep), check_rep=False)(
+        out_specs=(rep, rep, rep, rep), check_vma=False)(
         ye, info, x, probs, *sh_args)
     aux = {"moe_aux_loss": aux_loss, "max_violation": max_violation,
            "dropped_frac": dropped}
@@ -354,7 +353,6 @@ def _moe_decode_serve(params, x, cfg, cap, mesh):
     the serving layout's "expert"/"model" sharding, so the parameter
     bytes stay distributed and each element's contraction is untouched.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec
     m = cfg.moe
     B, S, d = x.shape
@@ -367,8 +365,8 @@ def _moe_decode_serve(params, x, cfg, cap, mesh):
         weights, experts, _ = _route({"router": router}, xf, m)
         return _dispatch_row(xf, weights, experts, E, K, cap)
 
-    xe, info = shard_map(dispatch, mesh=mesh, in_specs=(rep, rep),
-                         out_specs=(rep, (rep,) * 5), check_rep=False)(
+    xe, info = jax.shard_map(dispatch, mesh=mesh, in_specs=(rep, rep),
+                             out_specs=(rep, (rep,) * 5), check_vma=False)(
         xf, params["router"])
 
     # expert GEMM: explicitly pinned to the serving layout's expert-dim
@@ -382,9 +380,9 @@ def _moe_decode_serve(params, x, cfg, cap, mesh):
         up = jnp.einsum("ecd,edf->ecf", xe, wu)
         return jnp.einsum("ecf,efd->ecd", gate * up, wd)
 
-    ye = shard_map(expert_mlp, mesh=mesh,
-                   in_specs=(espec, espec, espec, espec), out_specs=espec,
-                   check_rep=False)(
+    ye = jax.shard_map(expert_mlp, mesh=mesh,
+                       in_specs=(espec, espec, espec, espec), out_specs=espec,
+                       check_vma=False)(
         xe, params["w_gate"], params["w_up"], params["w_down"])
 
     shared = m.num_shared_experts
@@ -400,7 +398,7 @@ def _moe_decode_serve(params, x, cfg, cap, mesh):
     sh_args = () if not shared else (
         params["shared"]["w_gate"], params["shared"]["w_up"],
         params["shared"]["w_down"], params["shared_gate"])
-    y = shard_map(combine, mesh=mesh,
-                  in_specs=(rep,) * (7 + len(sh_args)), out_specs=rep,
-                  check_rep=False)(ye, *info, xf, *sh_args)
+    y = jax.shard_map(combine, mesh=mesh,
+                      in_specs=(rep,) * (7 + len(sh_args)), out_specs=rep,
+                      check_vma=False)(ye, *info, xf, *sh_args)
     return y.reshape(B, S, d).astype(x.dtype)

@@ -38,7 +38,10 @@ _EXEC_POOL: Optional[mp.pool.Pool] = None
 def _get_pool() -> mp.pool.Pool:
     global _EXEC_POOL
     if _EXEC_POOL is None:
-        ctx = mp.get_context("fork")
+        # workers come from a fork server started clean, not from a fork of
+        # this process: by the time the first sandbox runs, JAX may hold
+        # the accelerator and its runtime threads, which a fork would copy
+        ctx = mp.get_context("forkserver")
         _EXEC_POOL = ctx.Pool(processes=4)
     return _EXEC_POOL
 

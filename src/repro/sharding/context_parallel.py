@@ -19,9 +19,6 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
-
-from repro.common.compat import axis_size
 
 NEG_INF = -1e30
 
@@ -57,7 +54,7 @@ def ring_attention_body(q, k, v, *, axis: str, causal: bool = True):
     """shard_map body: q,k,v are the *local* sequence chunks [B,S/N,H,hd]."""
     B, Sl, Hq, hd = q.shape
     scale = hd ** -0.5
-    n_dev = axis_size(axis)
+    n_dev = jax.lax.axis_size(axis)
     idx = jax.lax.axis_index(axis)
     q_off = idx * Sl
 
@@ -66,10 +63,6 @@ def ring_attention_body(q, k, v, *, axis: str, causal: bool = True):
     m = jnp.full((B, Hkv, G, Sl), NEG_INF, jnp.float32)
     l = jnp.zeros((B, Hkv, G, Sl), jnp.float32)
     acc = jnp.zeros((B, Hkv, G, Sl, hd), jnp.float32)
-    # mark the zero-init stats device-varying (they merge with varying data)
-    pvary = getattr(jax.lax, "pvary", None)
-    if pvary is not None:
-        m, l, acc = (pvary(x, (axis,)) for x in (m, l, acc))
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
 
     def step(carry, r):
@@ -95,12 +88,6 @@ def ring_attention(q, k, v, mesh: Mesh, *, axis: str = "model",
     """q,k,v: [B,S,H,hd] with S divisible by mesh.shape[axis]."""
     body = functools.partial(ring_attention_body, axis=axis, causal=causal)
     spec = P(None, axis, None, None)
-    try:
-        # check_rep's scan rule misjudges the ring carry on jax 0.4.x and
-        # rejects the backward pass; the checker itself suggests disabling
-        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec, check_rep=False)
-    except TypeError:  # newer jax: flag renamed/removed
-        fn = shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
-                       out_specs=spec)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)

@@ -1,6 +1,5 @@
 """launch.mesh: make_mesh / make_production_mesh / make_engine_meshes
-under forced host device counts (subprocess), plus the AxisType-optional
-compat shim for jax versions without jax.sharding.AxisType."""
+under forced host device counts (subprocess)."""
 import jax
 import pytest
 
@@ -8,21 +7,10 @@ from repro.launch import mesh as mesh_mod
 from tests.utils import check, run_with_devices
 
 
-# -- AxisType compat (in-process; single device is enough) -------------------
-
-
-def test_axis_kwargs_without_axistype(monkeypatch):
-    """Old-jax path: no AxisType symbol -> no axis_types kwarg, and mesh
-    construction still works."""
-    monkeypatch.setattr(mesh_mod, "AxisType", None)
-    assert mesh_mod._axis_kwargs(2) == {}
-    m = mesh_mod.make_mesh((1,), ("data",))
-    assert dict(m.shape) == {"data": 1}
+# -- axis types (in-process; single device is enough) ------------------------
 
 
 def test_axis_kwargs_with_axistype():
-    if mesh_mod.AxisType is None:
-        pytest.skip("installed jax has no AxisType")
     kw = mesh_mod._axis_kwargs(3)
     assert kw == {"axis_types": (mesh_mod.AxisType.Auto,) * 3}
 

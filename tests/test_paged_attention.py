@@ -103,3 +103,17 @@ def test_paged_kernel_spare_blocks_are_inert():
     out3 = attention_paged_decode(q, k_p2, v_p2, tables, pos)
     np.testing.assert_allclose(np.asarray(out3), np.asarray(out1),
                                rtol=2e-5, atol=2e-5)
+
+
+def test_paged_attention_ref_matches_dense_decode():
+    """The naive oracle ``kernels.ref.paged_attention_ref`` (gather the
+    table, full softmax over keys <= pos) agrees with dense decode on a
+    shuffled pool — it is what the Pallas kernel is held to on the chip."""
+    from repro.kernels.ref import paged_attention_ref
+    B, Hq, Hkv, hd, bs, max_blocks = 3, 8, 2, 16, 4, 5
+    q, k_d, v_d, k_p, v_p, tables, pos = _paged_case(
+        7, B, Hq, Hkv, hd, bs, max_blocks, [0, 9, 19])
+    ref = attention_decode(q, k_d, v_d, pos)
+    np.testing.assert_allclose(
+        np.asarray(paged_attention_ref(q, k_p, v_p, tables, pos)),
+        np.asarray(ref), rtol=2e-5, atol=2e-5)
