@@ -27,6 +27,7 @@ from typing import Dict, List, Optional, Sequence
 import numpy as np
 
 from repro.core.rollouts import Rollout, RolloutGroup
+from . import tracing
 from .engine import (GroupRequest, InferenceEngine, Request,
                      latency_snapshot)
 
@@ -330,6 +331,10 @@ class InferencePool:
                                           for e in self.engines),
             "prefix_cache_swept": sum(e.stats.prefix_cache_swept
                                       for e in self.engines),
+            # host seconds per phase of the engines' steps, summed
+            "phase_s": {p: sum(getattr(e.stats, p + "_s")
+                               for e in self.engines)
+                        for p in tracing.PHASES},
             "latency": self.latency_snapshot(),
         }
 

@@ -204,6 +204,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.configs.base import ModelConfig, ParallelConfig
+from repro.inference import tracing
 from repro.inference.cache_layout import CacheLayout
 from repro.models import (extend, extend_sample, extend_verify_sample,
                           fork_decode_rows, init_decode_state,
@@ -242,9 +243,8 @@ class Request:
     finished: bool = False
     finish_reason: str = ""
     # latency accounting (engine-stamped perf_counter seconds): submit
-    # time, first-token time, and one stamp per generated token
+    # time and one stamp per generated token (token_ts[0] is the first)
     submit_ts: float = 0.0
-    first_token_ts: float = 0.0
     last_token_ts: float = 0.0
     token_ts: List[float] = field(default_factory=list)
     # engine step at submission — the deadline-promotion age reference
@@ -354,6 +354,16 @@ class EngineStats:
     sched_promotions: int = 0    # rollout -> interactive deadline promotions
     sched_budget_deferrals: int = 0  # chunk advances deferred by the budget
     cancelled: int = 0           # requests finished with reason "cancelled"
+    # host seconds per phase of step() (tracing.PHASES); readback_s counts
+    # every blocking device read and nests in the phase that waits on it
+    step_s: float = 0.0          # the whole step() call
+    admit_s: float = 0.0         # _admit: prefills, extends, group forks
+    chunks_s: float = 0.0        # _advance_chunks
+    speculate_s: float = 0.0     # _speculate, drafting included
+    blocks_s: float = 0.0        # _ensure_decode_blocks + table flush
+    tick_s: float = 0.0          # the decode tick's dispatch (enqueue)
+    readback_s: float = 0.0      # blocking jax.device_get waits
+    record_s: float = 0.0        # per-slot bookkeeping after the tick
     # per-step occupancy trace for the Fig. 4 / utilization benchmark
     occupancy_trace: List[int] = field(default_factory=list)
     # latency measurement windows (seconds): TTFT = submit -> first token,
@@ -1329,12 +1339,22 @@ class InferenceEngine:
 
     def _decode_exec(self):
         """One fused decode tick; a single small host readback."""
-        with self._dispatch_ctx():
+        with self._phase("tick"), self._dispatch_ctx():
             (toks, lps, fin, self._last_token, self._active, self._gen,
              self.state, self._rng) = self._tick_fn(
                 self.params, self.state, self._last_token, self._active,
                 self._temps, self._gen, self._max_new, self._rng)
-        return jax.device_get((toks, lps, fin))
+        return self._readback((toks, lps, fin))
+
+    def _phase(self, name: str) -> tracing.phase:
+        """Time one phase of ``step`` into ``stats.<name>_s`` (and span it
+        as ``engine.<name>`` while ``tracing`` is switched on)."""
+        return tracing.phase(self.stats, name)
+
+    def _readback(self, x):
+        """Every blocking device-to-host read of the engine."""
+        with self._phase("readback"):
+            return jax.device_get(x)
 
     # ------------------------------------------------------------ internals
 
@@ -1895,7 +1915,7 @@ class InferenceEngine:
         slot_idx = np.array([slot], np.int32)
         toks, lps, st = self._extend_exec(gather_idx, tokens, ext_lens,
                                           start_pos, temps)
-        toks_h, lps_h = jax.device_get((toks, lps))
+        toks_h, lps_h = self._readback((toks, lps))
         tok, lp = int(toks_h[0]), float(lps_h[0])
         finished = (tok == self.eos_id) or (req.max_new_tokens <= 1)
         row_active = np.array([not finished], bool)
@@ -2216,7 +2236,7 @@ class InferenceEngine:
             if tails:
                 self.stats.cow_forks += k
         toks, lps, st = self._group_prefill_exec(tokens, plens, temps)
-        toks_h, lps_h = jax.device_get((toks, lps))
+        toks_h, lps_h = self._readback((toks, lps))
 
         slot_idx = np.full((R,), self.num_slots, np.int32)  # OOB rows drop
         slot_idx[:k] = slot_ids
@@ -2300,7 +2320,7 @@ class InferenceEngine:
                     f"slot {slot_ids[r]} re-admitted while holding blocks"
                 self._slot_blocks[slot_ids[r]] = block_lists[r]
         toks, lps, st = self._prefill_exec(tokens, plens, temps)
-        toks_h, lps_h = jax.device_get((toks, lps))
+        toks_h, lps_h = self._readback((toks, lps))
 
         slot_idx = np.full((R,), self.num_slots, np.int32)  # OOB rows drop
         slot_idx[:n] = slot_ids
@@ -2376,7 +2396,7 @@ class InferenceEngine:
             self._slot_len[sess.slot] = int(start_pos[r] + ext_lens[r])
         toks, lps, st = self._extend_exec(gather_idx, tokens, ext_lens,
                                           start_pos, temps)
-        toks_h, lps_h = jax.device_get((toks, lps))
+        toks_h, lps_h = self._readback((toks, lps))
 
         row_active = np.zeros((R,), bool)
         for r, req in enumerate(reqs):
@@ -2592,7 +2612,7 @@ class InferenceEngine:
             slot_idx[r] = slot
         toks, lps, st = self._extend_exec(gather_idx, tokens, ext_lens,
                                           start_pos, temps)
-        toks_h, lps_h = jax.device_get((toks, lps))
+        toks_h, lps_h = self._readback((toks, lps))
 
         row_active = np.zeros((R,), bool)
         deferred_free: List[int] = []
@@ -2691,7 +2711,6 @@ class InferenceEngine:
                 finished: bool) -> None:
         now = time.perf_counter()
         if not req.completion:
-            req.first_token_ts = now
             self.stats.ttft_window.append(now - req.submit_ts)
         else:
             self.stats.itl_window.append(now - req.last_token_ts)
@@ -2820,7 +2839,7 @@ class InferenceEngine:
             self.stats.spec_drafted_tokens += len(draft)
         toks, lps, st = self._verify_exec(gather_idx, tokens, ext_lens,
                                           start_pos, temps)
-        toks_h, lps_h = jax.device_get((toks, lps))
+        toks_h, lps_h = self._readback((toks, lps))
         self.stats.spec_rounds += 1
 
         row_active = np.zeros((R,), bool)
@@ -2930,7 +2949,14 @@ class InferenceEngine:
         by one chunk right after admission — chunk-tokens ride along
         with the decode tick instead of monopolizing it — and the
         per-tick token budget (when set) is claimed by chunk writes
-        first, speculation rounds second."""
+        first, speculation rounds second.
+
+        Each phase adds its host seconds to ``stats.<phase>_s``
+        (``tracing.PHASES``)."""
+        with self._phase("step"):
+            return self._step()
+
+    def _step(self) -> int:
         self._step_count += 1
         if self._budget_classes is not None:
             self._budget_left = dict(self._budget_classes)
@@ -2938,10 +2964,13 @@ class InferenceEngine:
             self._budget_left = {0: self.prefill_token_budget}
         else:
             self._budget_left = None
-        self._admit()
-        self._advance_chunks()
+        with self._phase("admit"):
+            self._admit()
+        with self._phase("chunks"):
+            self._advance_chunks()
         self._overflow_full_slots()
-        covered, spec_tokens = self._speculate()
+        with self._phase("speculate"):
+            covered, spec_tokens = self._speculate()
         # a verify commit can land a slot exactly at max_seq: overflow it
         # before the tick (same guard, same reason — the tick's write
         # would clamp and corrupt the cache)
@@ -2959,37 +2988,43 @@ class InferenceEngine:
             self.stats.spec_saved_ticks += 1
             self._sync_kv_stats()
             return spec_tokens
-        self._ensure_decode_blocks()
+        with self._phase("blocks"):
+            self._ensure_decode_blocks()
         # pool starvation may have overflow-finished slots: re-derive the
         # tick's participant list after the block guarantee
         active = [i for i, r in enumerate(self.slots) if r is not None]
         if not active:
             self._sync_kv_stats()
             return spec_tokens
-        self._flush_table_updates()
+        with self._phase("blocks"):
+            self._flush_table_updates()
         toks_h, lps_h, fin_h = self._decode_exec()
-        for i in active:
-            req = self.slots[i]
-            if self.prefix_cache:
-                # the tick fed the previous sample (completion[-1] before
-                # this _record): that's the token whose K/V it wrote
-                self._slot_toks[i].append(int(req.completion[-1]))
-            self._slot_len[i] += 1          # this tick wrote K/V at wpos
-            self._record(req, int(toks_h[i]), float(lps_h[i]), bool(fin_h[i]))
-            self._publish_slot_blocks(i)    # tail block may just have filled
-            if req.finished:
-                self._finish(req)
-                self.slots[i] = None
-                sess = self._session_of(req)
-                if sess is None or sess.slot != i:
-                    # no live session to park for -> free the slot (and,
-                    # when paged, return its KV blocks to the pool —
-                    # published full blocks retire into the prefix cache)
-                    self._slot_session[i] = None
-                    if self._kvacct:
-                        self._free_slot_blocks(i)
-        self.stats.decode_steps += 1
-        self._sync_kv_stats()
+        with self._phase("record"):
+            for i in active:
+                req = self.slots[i]
+                if self.prefix_cache:
+                    # the tick fed the previous sample (completion[-1]
+                    # before this _record): that's the token whose K/V it
+                    # wrote
+                    self._slot_toks[i].append(int(req.completion[-1]))
+                self._slot_len[i] += 1          # this tick wrote K/V at wpos
+                self._record(req, int(toks_h[i]), float(lps_h[i]),
+                             bool(fin_h[i]))
+                self._publish_slot_blocks(i)    # tail block may have filled
+                if req.finished:
+                    self._finish(req)
+                    self.slots[i] = None
+                    sess = self._session_of(req)
+                    if sess is None or sess.slot != i:
+                        # no live session to park for -> free the slot
+                        # (and, when paged, return its KV blocks to the
+                        # pool — published full blocks retire into the
+                        # prefix cache)
+                        self._slot_session[i] = None
+                        if self._kvacct:
+                            self._free_slot_blocks(i)
+            self.stats.decode_steps += 1
+            self._sync_kv_stats()
         return spec_tokens + len(active)
 
     def run_until_idle(self, max_steps: int = 10_000) -> None:

@@ -141,6 +141,7 @@ def main():
 
     from repro.common.compile_cache import use_compile_cache
     from repro.data import TOKENIZER
+    from repro.inference import tracing
     use_compile_cache()
     mesh = None
     if args.mesh is not None:
@@ -236,6 +237,12 @@ def main():
                 stats["mesh_shapes"], stats["kv_bytes_per_shard"])):
             print(f"engine {i} mesh [{shape}]: "
                   f"{per_shard} KV bytes per device shard")
+    steps = sum(len(o) for o in stats["occupancy"])
+    if steps:
+        phase_s = stats["phase_s"]
+        print(f"host ms per engine step: {phase_s['step'] * 1e3 / steps:.2f}"
+              " (" + ", ".join(f"{p} {phase_s[p] * 1e3 / steps:.2f}"
+                               for p in tracing.PHASES[1:]) + ")")
     print(f"mean slot occupancy: {np.mean(occ):.2f}/{args.slots} "
           f"(continuous batching keeps slots saturated)")
     for r in done[:3]:

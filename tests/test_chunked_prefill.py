@@ -197,7 +197,7 @@ def test_interactive_class_jumps_queue(setup):
     eng.submit(inter)
     done = _drain(eng)
     assert len(done) == 3
-    assert done[2].first_token_ts < done[1].first_token_ts
+    assert done[2].token_ts[0] < done[1].token_ts[0]
 
 
 def test_deadline_promotion_unstarves_rollouts(setup):
@@ -218,7 +218,7 @@ def test_deadline_promotion_unstarves_rollouts(setup):
     eng.submit(inter)
     done = _drain(eng)
     assert eng.stats.sched_promotions == 1
-    assert done[1].first_token_ts < done[2].first_token_ts
+    assert done[1].token_ts[0] < done[2].token_ts[0]
 
 
 def test_prefill_budget_paces_chunks_and_caps_spec(setup):
@@ -353,7 +353,7 @@ def test_latency_accounting_and_windows(setup):
         pool.step()
     pool.drain_requests()
     for r in reqs:
-        assert r.first_token_ts >= r.submit_ts > 0.0
+        assert r.token_ts[0] >= r.submit_ts > 0.0
         assert len(r.token_ts) == len(r.completion)
     snap = eng.stats.snapshot()
     assert snap["ttft_n"] == 4 and snap["itl_n"] > 0
