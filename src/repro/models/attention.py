@@ -299,6 +299,13 @@ def attention_decode(q, k_cache, v_cache, pos, *, window=0, scale=None):
     return out.reshape(B, 1, Hq, hd).astype(q.dtype)
 
 
+def _linear_rows(blocks):
+    """Gathered blocks ``[B, max_blocks, bs, Hkv, hd]`` as dense rows
+    ``[B, max_blocks·bs, Hkv, hd]``."""
+    B, mb, bs = blocks.shape[:3]
+    return blocks.reshape(B, mb * bs, *blocks.shape[3:])
+
+
 def attention_paged_decode(q, k_pool, v_pool, block_tables, pos, *,
                            window=0, scale=None):
     """One-token decode against a *paged* KV cache — XLA gather fallback.
@@ -318,25 +325,27 @@ def attention_paged_decode(q, k_pool, v_pool, block_tables, pos, *,
     (``repro.kernels.paged_attention``) computes the same thing without
     ever materializing the gathered temporary.
     """
-    B = q.shape[0]
-    nb, bs, Hkv, hd = k_pool.shape
-    S = block_tables.shape[1] * bs
-    k = k_pool[block_tables].reshape(B, S, Hkv, hd)
-    v = v_pool[block_tables].reshape(B, S, Hkv, hd)
-    return attention_decode(q, k, v, pos, window=window, scale=scale)
+    return attention_decode(q, _linear_rows(k_pool[block_tables]),
+                            _linear_rows(v_pool[block_tables]), pos,
+                            window=window, scale=scale)
 
 
-def attn_paged_decode_apply(params, x, k_pool, v_pool, block_tables, pos,
-                            write_block, write_off, cfg, *,
+def attn_paged_decode_apply(params, x, k_pool, v_pool, layer, block_tables,
+                            pos, write_block, write_off, cfg, *,
                             use_pallas=False):
     """One-token decode attention over the shared block pool.
 
-    The paged sibling of ``attn_decode_apply``: inserts the new token's
-    K/V at physical ``(write_block[b], write_off[b])`` — the caller maps
-    ``pos`` through the block table and masks inactive rows to an
-    out-of-bounds block id, so their writes drop instead of corrupting
-    blocks owned (or shared, post-fork) by other rows — then attends
-    through the block table. Returns (out [B,1,d], k_pool, v_pool).
+    The paged sibling of ``attn_decode_apply``. ``k_pool``/``v_pool`` are
+    the stacked pools ``[L, num_blocks, block_size, Hkv, hd]`` that the
+    decode layer scan carries; this layer reads and writes them at
+    ``layer``. Inserts the new token's K/V at physical ``(layer,
+    write_block[b], write_off[b])`` — the caller maps ``pos`` through the
+    block table and masks inactive rows to an out-of-bounds block id, so
+    their writes drop instead of corrupting blocks owned (or shared,
+    post-fork) by other rows — then gathers this layer's blocks through
+    the block table straight from the written pool. The scatter is in
+    place, and no pool-sized slice is copied. Returns (out [B,1,d],
+    k_pool, v_pool).
 
     Ring (window-sized) caches are excluded by the engine's paging gate:
     the linear block table is the only slot→position mapping here, and
@@ -347,17 +356,20 @@ def attn_paged_decode_apply(params, x, k_pool, v_pool, block_tables, pos,
     if pos.ndim == 0:
         pos = jnp.broadcast_to(pos, (B,))
     q, k, v = _project_qkv(params, x, pos[:, None], cfg)
-    k_pool = k_pool.at[write_block, write_off].set(
+    k_pool = k_pool.at[layer, write_block, write_off].set(
         k[:, 0].astype(k_pool.dtype), mode="drop")
-    v_pool = v_pool.at[write_block, write_off].set(
+    v_pool = v_pool.at[layer, write_block, write_off].set(
         v[:, 0].astype(v_pool.dtype), mode="drop")
     if use_pallas:
         from repro.kernels import ops as kops
-        out = kops.paged_attention(q, k_pool, v_pool, block_tables, pos,
+        out = kops.paged_attention(q, k_pool[layer], v_pool[layer],
+                                   block_tables, pos,
                                    window=cfg.sliding_window)
     else:
-        out = attention_paged_decode(q, k_pool, v_pool, block_tables, pos,
-                                     window=cfg.sliding_window)
+        out = attention_decode(
+            q, _linear_rows(k_pool[layer, block_tables]),
+            _linear_rows(v_pool[layer, block_tables]), pos,
+            window=cfg.sliding_window)
     out = _serve_gather_heads(out.reshape(B, 1, cfg.q_dim)) @ params["wo"]
     return out, k_pool, v_pool
 

@@ -936,17 +936,20 @@ def paged_write_rows(state, rows, slot_idx, src_pos, blk_pos, off_pos,
     return new
 
 
-def _decoder_layer_paged_decode(lp, x, pos, caches, table, write_block,
-                                write_off, cfg, pcfg):
+def _decoder_layer_paged_decode(lp, x, pos, caches, pools, layer, table,
+                                write_block, write_off, cfg, pcfg):
     """One layer, one token, against the block pool. The paged sibling of
-    ``_decoder_layer_decode``; hybrid layers run their SSM mixer against
-    the dense per-row state rows alongside the paged attention."""
+    ``_decoder_layer_decode``: ``pools`` holds the stacked ``[L, ...]``
+    K/V pools, read and written at ``layer``; ``caches`` holds this
+    layer's slice of the per-row caches, against which hybrid layers run
+    their SSM mixer alongside the paged attention. Returns (x, pools,
+    caches)."""
     new = dict(caches)
     h = rmsnorm(x, lp["ln1"], cfg.rms_eps)
     attn_out, kp, vp = attn_paged_decode_apply(
-        lp["attn"], h, caches["k"], caches["v"], table, pos,
+        lp["attn"], h, pools["k"], pools["v"], layer, table, pos,
         write_block, write_off, cfg, use_pallas=pcfg.use_pallas)
-    new["k"], new["v"] = kp, vp
+    pools = {"k": kp, "v": vp}
     if cfg.parallel_ssm:
         ssm_out, st = ssm_decode_step(lp["ssm"], h,
                                       {"conv": caches["ssm_conv"],
@@ -965,12 +968,19 @@ def _decoder_layer_paged_decode(lp, x, pos, caches, table, write_block,
         x = x + moe_decode_apply(lp["moe"], h, cfg)
     elif cfg.d_ff:
         x = x + mlp_apply(lp["mlp"], rmsnorm(x, lp["ln2"], cfg.rms_eps))
-    return x, new
+    return x, pools, new
 
 
 def paged_serve_step(params, state, token, active, cfg: ModelConfig,
                      pcfg=DEFAULT_PARALLEL):
     """One decode step against the paged state. token/active: [B].
+
+    The K/V pools go through the layer scan as carried state: each layer
+    scatters its new rows into the stacked pool at ``(layer, block,
+    offset)`` and reads its blocks back through the table from the same
+    buffer, so a donated pool is updated in place and nothing of pool
+    size is copied in a tick. Per-row caches (hybrid SSM state,
+    cross-attention K/V) are still scanned per layer.
 
     ``active`` masks the K/V write: inactive rows (empty or parked slots)
     route their write to an out-of-bounds block id so they can never
@@ -996,19 +1006,26 @@ def paged_serve_step(params, state, token, active, cfg: ModelConfig,
     if cfg.rope_theta == 0.0:
         x = x + sinusoidal_positions(pos[:, None], cfg.d_model).astype(x.dtype)
 
-    per_layer = {k: state[k] for k in _CACHE_KEYS if k in state}
+    pools = {k: state[k] for k in _PAGED_POOL_KEYS}
+    per_layer = {k: state[k] for k in _CACHE_KEYS
+                 if k in state and k not in _PAGED_POOL_KEYS}
 
-    def body(x, inp):
-        lp, caches = inp
-        x, new = _decoder_layer_paged_decode(
-            lp, x, pos, caches, table, write_block, write_off, cfg, pcfg)
-        return x, new
+    def body(carry, inp):
+        x, pools = carry
+        lp, caches, layer = inp
+        x, pools, new = _decoder_layer_paged_decode(
+            lp, x, pos, caches, pools, layer, table, write_block, write_off,
+            cfg, pcfg)
+        return (x, pools), new
 
-    x, new_caches = jax.lax.scan(body, x, (params["layers"], per_layer))
+    layers = jnp.arange(state["k"].shape[0])
+    (x, pools), new_caches = jax.lax.scan(
+        body, (x, pools), (params["layers"], per_layer, layers))
     new_caches = _freeze_inactive_recurrent(new_caches, per_layer, active)
     x = rmsnorm(x, params["final_norm"], cfg.rms_eps)
     logits = (x[:, 0] @ head_weights(params, cfg)).astype(jnp.float32)
     new_state = dict(state)
+    new_state.update(pools)
     new_state.update(new_caches)
     new_state["pos"] = pos + 1
     return logits, new_state

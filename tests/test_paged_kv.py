@@ -20,7 +20,8 @@ from repro.configs import get_config
 from repro.data import TOKENIZER
 from repro.inference import GroupRequest, InferenceEngine, Request
 from repro.inference.engine import BlockAllocator
-from repro.models import init_params
+from repro.models import (init_paged_state, init_params, paged_sample_step,
+                          prefill, sample_step)
 from tests.utils import given, settings, st
 
 BS = 8  # block size used throughout (divides every max_seq below)
@@ -458,3 +459,80 @@ def test_spec_workload_never_leaks_blocks(setup, plen, max_new, draft):
     assert eng.stats.spec_rounds > 0, "workload must actually speculate"
     assert eng.allocator.in_use == 0
     assert eng.stats.kv_blocks_in_use == 0
+
+
+# ------------------------------------------------ model-level paged decode
+
+
+@pytest.mark.parametrize("arch", PAGEABLE_FAMILIES + ["qwen2-moe-a2.7b:reduced"])
+def test_paged_tick_matches_dense_rows_and_spares_unowned_blocks(arch):
+    """``paged_sample_step`` carries the stacked pool through its layer
+    scan and writes each layer's rows in place. Over a shuffled block
+    table with one inactive row, a few ticks must give the active rows
+    the tokens and logprobs of ``sample_step`` on the equivalent dense
+    rows, leave their blocks holding the dense rows' K/V, and leave every
+    block that no active row owns — the inactive row's and the spares —
+    bit-for-bit unchanged: the masked write drops, it never lands."""
+    cfg = dataclasses.replace(get_config(arch), num_layers=2)
+    params = init_params(jax.random.PRNGKey(0), cfg, dtype=jnp.float32)
+    B, max_seq, ticks = 4, 128, 3
+    mb = max_seq // BS
+    lens = np.array([5, 17, 9, 30], np.int32)
+    toks = np.zeros((B, int(lens.max())), np.int32)
+    for b, n in enumerate(lens):
+        toks[b, :n] = _prompt(int(n), seed=b)
+    _, dense = prefill(params, {"tokens": jnp.asarray(toks),
+                                "prompt_lens": jnp.asarray(lens)},
+                       cfg, max_seq=max_seq)
+    active = jnp.asarray([True, True, False, True])
+    temps = jnp.asarray([1.0, 0.0, 1.0, 1.0], jnp.float32)
+
+    nb = B * mb + 5                        # five spare blocks nobody owns
+    rs = np.random.RandomState(0)
+    table = rs.permutation(nb)[:B * mb].reshape(B, mb).astype(np.int32)
+    paged = init_paged_state(cfg, B, nb, BS, mb, dtype=jnp.float32)
+    paged["pos"] = dense["pos"]
+    paged["block_tables"] = jnp.asarray(table)
+    for key in ("k", "v"):
+        # garbage everywhere, then each row's dense K/V in its blocks
+        pool = rs.standard_normal(paged[key].shape).astype(np.float32)
+        rows = np.asarray(dense[key])
+        L, _, _, H, hd = rows.shape
+        pool[:, table] = rows.reshape(L, B, mb, BS, H, hd)
+        paged[key] = jnp.asarray(pool)
+    for key in dense:
+        if key not in ("pos", "k", "v"):
+            paged[key] = dense[key]
+    owned = np.zeros(nb, bool)
+    owned[table[np.asarray(active)]] = True
+    before = {key: np.asarray(paged[key]) for key in ("k", "v")}
+
+    dense_step = jax.jit(lambda s, t, r: sample_step(
+        params, s, t, temps, r, cfg, active=active))
+    paged_step = jax.jit(lambda s, t, r: paged_sample_step(
+        params, s, t, active, temps, r, cfg))
+    token = jnp.asarray(toks[np.arange(B), lens - 1])
+    rng_d = rng_p = jax.random.PRNGKey(3)
+    act = np.asarray(active)
+    for _ in range(ticks):
+        t_d, lp_d, dense, rng_d = dense_step(dense, token, rng_d)
+        t_p, lp_p, paged, rng_p = paged_step(paged, token, rng_p)
+        np.testing.assert_array_equal(np.asarray(t_p)[act],
+                                      np.asarray(t_d)[act])
+        np.testing.assert_array_equal(np.asarray(lp_p)[act],
+                                      np.asarray(lp_d)[act])
+        token = jnp.where(active, t_d, token)
+    np.testing.assert_array_equal(np.asarray(paged["pos"]),
+                                  np.asarray(dense["pos"]))
+    end = np.asarray(dense["pos"])
+    for key in ("k", "v"):
+        pool = np.asarray(paged[key])
+        np.testing.assert_array_equal(pool[:, ~owned], before[key][:, ~owned])
+        rows = pool[:, table].reshape(np.asarray(dense[key]).shape)
+        for b in np.flatnonzero(act):
+            np.testing.assert_array_equal(rows[:, b, :end[b]],
+                                          np.asarray(dense[key])[:, b, :end[b]])
+    for key in dense:
+        if key.startswith("ssm"):          # per-row caches stay scanned
+            np.testing.assert_array_equal(np.asarray(paged[key]),
+                                          np.asarray(dense[key]))

@@ -14,6 +14,8 @@ file must not try.
 """
 import dataclasses
 import functools
+import math
+import re
 
 import jax
 import jax.numpy as jnp
@@ -111,10 +113,12 @@ def test_ssd_scan_compiles_natively(one_chip):
         _spec(one_chip, (B, nh, hd, n), f32))
 
 
-def test_paged_decode_tick_fits_one_chip(one_chip):
+@pytest.fixture(scope="module")
+def paged_tick(one_chip):
     """The engine's fused paged decode tick for minitron-4b at full width
-    (bf16, vocab 256000), cut to 2 layers, with 8 slots and max_seq 1024:
-    compiles for the chip and needs less than its 16 GiB."""
+    (bf16, vocab 256000), cut to 2 layers, with 8 slots and max_seq 1024
+    (512 blocks of 16), its state donated as the engine donates it.
+    Returns (compiled, pool shape)."""
     cfg = dataclasses.replace(get_config("minitron-4b"), num_layers=2)
     slots, max_seq, bs = 8, 1024, 16
 
@@ -134,6 +138,12 @@ def test_paged_decode_tick_fits_one_chip(one_chip):
         _spec(one_chip, (slots,), jnp.bool_),
         _spec(one_chip, (slots,), jnp.float32),
         _spec(one_chip, (2,), jnp.uint32)).compile()
+    return compiled, state["k"].shape
+
+
+def test_paged_decode_tick_fits_one_chip(paged_tick):
+    """The tick compiles for the chip and needs less than its 16 GiB."""
+    compiled, _ = paged_tick
     mem = compiled.memory_analysis()
     need = (mem.argument_size_in_bytes + mem.output_size_in_bytes
             - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
@@ -141,3 +151,22 @@ def test_paged_decode_tick_fits_one_chip(one_chip):
     # the params alone are ~3.3 GB at this cut: the arguments were sized
     # at full width, not at a toy width
     assert mem.argument_size_in_bytes > 3 * 10**9
+
+
+def test_paged_decode_tick_updates_pool_in_place(paged_tick):
+    """The K/V pools go through the layer scan as carried state, so the
+    donated pool is written in place: the tick's scratch memory is less
+    than one pool, and no ``copy`` or ``dynamic-update-slice`` produces a
+    pool-shaped buffer (a pool fed to the scan as input and taken back as
+    output costs a second pool and a copy of each into the output)."""
+    compiled, pool_shape = paged_tick
+    pool_bytes = math.prod(pool_shape) * jnp.dtype(jnp.bfloat16).itemsize
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < pool_bytes, (temp, pool_bytes)
+    pool_type = "bf16[" + ",".join(map(str, pool_shape)) + "]"
+    copies = [line.strip()[:160]
+              for line in compiled.as_text().splitlines()
+              if re.search(r"=\s*" + re.escape(pool_type), line)
+              and re.search(r"\b(copy|copy-done|dynamic-update-slice)\(|"
+                            r"dynamic-update-slice_fusion", line)]
+    assert not copies, copies
