@@ -13,14 +13,21 @@ runs once over each whole sequence; every served token is then held to it:
 
 The limits live in the configuration file under ``check``; PERF.md gives
 the readings each was set from.
+
+The reference is ``bench/reference.py`` unless the configuration file names
+its own copy under ``"reference"`` (a path under ``bench/``), a module with
+the same ``Weights``, ``hidden_states`` and ``head_readings``.
 """
 from __future__ import annotations
 
 import gc
 from dataclasses import dataclass
+from pathlib import Path, PurePosixPath
 from typing import List
 
 import numpy as np
+
+from bench.harness import ROOT, BenchError, load_module
 
 
 @dataclass
@@ -40,12 +47,27 @@ class Sequence:
                    greedy=np.full(len(comp), req.temperature <= 0))
 
 
+def reference_module(model: dict, root: Path = ROOT):
+    """The configuration's plain reference: the module its file names under
+    ``"reference"``, or ``bench/reference.py``."""
+    path = model.get("reference")
+    if path is None:
+        from bench import reference
+        return reference
+    parts = PurePosixPath(path).parts
+    if parts[:1] != ("bench",) or ".." in parts:
+        raise BenchError(f"{model['name']}: reference {path!r} does not lie "
+                         "under bench/")
+    return load_module(root / path, "bench_reference_" + "_".join(
+        parts[1:]).removesuffix(".py").replace(".", "_").replace("-", "_"))
+
+
 def readings(model: dict, seed: int, sample: List[Sequence],
-             control: bool = False) -> dict:
+             control: bool = False, root: Path = ROOT) -> dict:
     """The reference's readings of every served token of ``sample`` (and,
     with ``control``, the fp8 control's at the same positions)."""
     import jax.numpy as jnp
-    from bench.reference import Weights, head_readings, hidden_states
+    ref = reference_module(model, root)
     if not sample:
         raise ValueError("no finished sequence to check")
     # rows padded to the engine's max_seq, so that the reference compiles
@@ -55,9 +77,9 @@ def readings(model: dict, seed: int, sample: List[Sequence],
     rows = np.zeros((len(sample), width - 1), np.int32)
     for i, s in enumerate(sample):
         rows[i, :len(s.tokens) - 1] = s.tokens[:-1]
-    weights = Weights(model, seed)
+    weights = ref.Weights(model, seed)
     quants = (None, "fp8") if control else (None,)
-    hs = hidden_states(weights, rows, quants)
+    hs = ref.hidden_states(weights, rows, quants)
     at_r = np.concatenate([np.full(len(s.served), i)
                            for i, s in enumerate(sample)])
     at_p = np.concatenate([s.served - 1 for s in sample])
@@ -66,8 +88,8 @@ def readings(model: dict, seed: int, sample: List[Sequence],
     del hs
     gc.collect()
     head = weights.head()
-    out = head_readings(head, toks, picked[0],
-                        picked[1] if control else None)
+    out = ref.head_readings(head, toks, picked[0],
+                            picked[1] if control else None)
     out["served_lp"] = np.concatenate([s.logprobs for s in sample])
     out["greedy"] = np.concatenate([s.greedy for s in sample])
     out["tokens"] = toks
@@ -101,12 +123,12 @@ def judge(n: dict, limits: dict) -> dict:
 
 
 def check_sample(model: dict, seed: int, sample: List[Sequence],
-                 control: bool = False) -> dict:
+                 control: bool = False, root: Path = ROOT) -> dict:
     """The comparison that decides ``correct``. With ``control`` the fp8
     control takes the program's place, at the same served tokens; it has to
     come out not correct."""
     try:
-        r = readings(model, seed, sample, control=control)
+        r = readings(model, seed, sample, control=control, root=root)
     except ValueError as e:
         return {"correct": False, "compared": {"error": str(e)}}
     return judge(numbers(r, model["vocab_size"], control), model["check"])

@@ -194,28 +194,122 @@ MODEL_KEYS = {
 }
 
 
+# the same for an expert layer (``num_experts`` in the file): the program's
+# MoEConfig fields; a non-zero shared expert width makes one shared expert
+EXPERT_KEYS = {
+    "num_experts": "num_experts", "num_experts_per_tok": "top_k",
+    "moe_intermediate_size": "expert_d_ff",
+    "shared_expert_intermediate_size": "shared_d_ff",
+    "norm_topk_prob": "norm_topk_prob",
+}
+# published keys that change the maths and have no program field: a file
+# may give them only with the value the program computes
+FIXED_KEYS = {"hidden_act": "silu", "decoder_sparse_step": 1,
+              "mlp_only_layers": []}
+
+
+def _program_fields(config: dict):
+    """The file's ``"program"`` object split into ModelConfig and MoEConfig
+    fields: what the published configuration does not name, set by the
+    program's own field names. A name of neither, or one that the
+    published keys already set, is refused."""
+    from dataclasses import fields
+
+    from repro.configs.base import ModelConfig, MoEConfig
+    taken = set(MODEL_KEYS.values()) | set(EXPERT_KEYS.values()) | {
+        "name", "family", "source", "dtype", "moe", "num_shared_experts"}
+    model_fields = {f.name for f in fields(ModelConfig)}
+    expert_fields = {f.name for f in fields(MoEConfig)}
+    top, moe = {}, {}
+    for key, value in config.get("program", {}).items():
+        if key in taken:
+            raise BenchError(f"{config['name']}: program field {key!r} is "
+                             "set from the published keys")
+        if key in model_fields:
+            top[key] = value
+        elif key in expert_fields and config.get("num_experts"):
+            moe[key] = value
+        elif key in expert_fields:
+            raise BenchError(f"{config['name']}: program field {key!r} is "
+                             "an expert field, and the file has no experts")
+        else:
+            raise BenchError(f"{config['name']}: the program has no field "
+                             f"{key!r} to set")
+    return top, moe
+
+
 def model_config(config: dict):
     """The program's ModelConfig of a configuration file, every size taken
     from the file: the file holds the configuration as it is run."""
-    from repro.configs.base import ModelConfig
+    from repro.configs.base import ModelConfig, MoEConfig
+    for key, value in FIXED_KEYS.items():
+        if key in config and config[key] != value:
+            raise BenchError(f"{config['name']}: {key}={config[key]!r}, but "
+                             f"the program computes only {value!r}")
+    top, moe_fields = _program_fields(config)
+    moe = None
     if config.get("num_experts"):
-        raise BenchError(f"{config['name']}: expert layers are not built "
-                         "from a configuration file yet")
+        missing = [key for key in EXPERT_KEYS if key not in config]
+        if missing:
+            raise BenchError(f"{config['name']}: an expert configuration "
+                             f"needs {missing} (a shared width of 0 for none)")
+        moe_fields.update({attr: config[key]
+                           for key, attr in EXPERT_KEYS.items()})
+        moe = MoEConfig(num_shared_experts=int(bool(moe_fields["shared_d_ff"])),
+                        **moe_fields)
     return ModelConfig(
-        name=config["name"], family="dense", source=config["source"],
-        dtype=config["dtype"],
+        name=config["name"], family="moe" if moe else "dense",
+        source=config["source"], dtype=config["dtype"], moe=moe, **top,
         **{attr: config[key] for key, attr in MODEL_KEYS.items()})
 
 
+def _expert_shapes(config: dict) -> dict:
+    """Global shapes of one configuration's served expert stacks, layers
+    first: [L, E, d, f] and the like."""
+    L, d, E = (config["num_hidden_layers"], config["hidden_size"],
+               config["num_experts"])
+    f = config["moe_intermediate_size"]
+    shapes = {("router",): (L, d, E), ("w_gate",): (L, E, d, f),
+              ("w_up",): (L, E, d, f), ("w_down",): (L, E, f, d)}
+    sf = config["shared_expert_intermediate_size"]
+    if sf:
+        shapes.update({("shared", "w_gate"): (L, d, sf),
+                       ("shared", "w_up"): (L, d, sf),
+                       ("shared", "w_down"): (L, sf, d),
+                       ("shared_gate",): (L, d, 1)})
+    return shapes
+
+
 def check_config(config: dict, cfg, params) -> None:
-    for key, attr in MODEL_KEYS.items():
-        if getattr(cfg, attr) != config[key]:
-            raise BenchError(f"{config['name']}: {key}={config[key]} but the "
-                             f"program runs {attr}={getattr(cfg, attr)}")
+    """Refuse a run whose program does not run what the file says: every
+    published key, every ``"program"`` field, the served dtype and, for an
+    expert layer, the served expert stacks' global shapes."""
+    name = config["name"]
+    pairs = [(key, config[key], attr, getattr(cfg, attr))
+             for key, attr in MODEL_KEYS.items()]
+    if config.get("num_experts"):
+        pairs += [(key, config[key], attr, getattr(cfg.moe, attr))
+                  for key, attr in EXPERT_KEYS.items()]
+    for key, value in config.get("program", {}).items():
+        held = cfg.moe if hasattr(cfg.moe, key) else cfg
+        pairs.append((f"program.{key}", value, key, getattr(held, key)))
+    for key, want, attr, runs in pairs:
+        if runs != want:
+            raise BenchError(f"{name}: {key}={want} but the program runs "
+                             f"{attr}={runs}")
     served = str(params["embed"].dtype)
     if served != config["dtype"]:
-        raise BenchError(f"{config['name']}: dtype={config['dtype']} but the "
+        raise BenchError(f"{name}: dtype={config['dtype']} but the "
                          f"program serves {served}")
+    if config.get("num_experts"):
+        for path, want in _expert_shapes(config).items():
+            leaf = params["layers"]["moe"]
+            for p in path:
+                leaf = leaf[p]
+            if tuple(leaf.shape) != want:
+                raise BenchError(f"{name}: the program serves moe."
+                                 f"{'.'.join(path)} of shape "
+                                 f"{tuple(leaf.shape)}, the file says {want}")
 
 
 def build_pool(config: dict, seed: int):
@@ -487,9 +581,9 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
             x.delete()
     del pool, traffic
     gc.collect()
-    checker = checker or default_checker
     t_check = time.perf_counter()
-    check = checker(cell.config, seed, sample)
+    check = (checker(cell.config, seed, sample) if checker
+             else default_checker(cell.config, seed, sample, cell.root))
     print(f"check: {time.perf_counter() - t_check:.1f} s over "
           f"{len(sample)} sequences", flush=True)
     result = {
@@ -509,6 +603,7 @@ def run_cell(cell: Cell, *, seed: int, seconds: float, trace: bool,
     return result
 
 
-def default_checker(config: dict, seed: int, sample) -> dict:
+def default_checker(config: dict, seed: int, sample,
+                    root: Path = ROOT) -> dict:
     from bench.check import check_sample
-    return check_sample(config, seed, sample)
+    return check_sample(config, seed, sample, root=root)

@@ -1,9 +1,10 @@
 """The decode ticks' least time over the window's seconds. A tick's least
-time is the larger of its FLOPs over the peak and its bytes over the
-bandwidth (``bench/work.py``): every weight once, and the K/V of each
-request's live positions. The requests a tick served, and their live
-lengths, come from the harness's record: a request's token ``j >= 1``
-stamped inside a ``pool.step()`` came from that step's tick."""
+time is the larger of its FLOPs over the cell's chips' summed peak and its
+bytes over their summed bandwidth (``bench/work.py``): every weight it uses
+once, and the K/V of each request's live positions. The requests a tick
+served, and their live lengths, come from the harness's record: a
+request's token ``j >= 1`` stamped inside a ``pool.step()`` came from that
+step's tick."""
 import bisect
 
 from bench import work
@@ -23,6 +24,6 @@ def compute(rec):
             k = bisect.bisect_right(starts, ts) - 1
             if k >= 0 and ts <= rec.steps[k][1]:
                 live[k].append(it.ctx + j)
-    least = sum(work.least_seconds(rec.model, lv, rec.peaks)
+    least = sum(work.least_seconds(rec.model, lv, rec.peaks, rec.chips)
                 for lv in live if lv)
     return 100.0 * least / rec.seconds

@@ -178,6 +178,115 @@ def test_new_cell_from_new_files_and_an_entry(tmp_path):
     assert cell.reader("ticks_per_s.rollout_g8").compute(rec) == 1.5
 
 
+# a small expert configuration, at the sizes of qwen2-moe-a2.7b:reduced, as
+# a later change would add it: its file, its own copy of the reference, and
+# entries in BENCHMARK.json
+TINY_MOE = {
+    "name": "tiny-moe",
+    "source": "https://huggingface.co/Qwen/Qwen1.5-MoE-A2.7B",
+    "chips": 1, "mesh": None, "dtype": "float32",
+    "num_hidden_layers": 2, "hidden_size": 256, "intermediate_size": 512,
+    "num_attention_heads": 4, "num_key_value_heads": 4, "head_dim": 64,
+    "vocab_size": 512, "hidden_act": "silu", "rms_norm_eps": 1e-05,
+    "rope_theta": 1000000.0, "sliding_window": 0,
+    "tie_word_embeddings": False, "num_experts": 4, "num_experts_per_tok": 2,
+    "moe_intermediate_size": 128, "shared_expert_intermediate_size": 128,
+    "norm_topk_prob": False, "decoder_sparse_step": 1, "mlp_only_layers": [],
+    "program": {"qk_norm": False},
+    "reference": "bench/references/tiny-moe.py",
+    "reduced": [], "engine": {
+        "slots": 4, "max_seq": 128, "kv_block_size": 16, "num_kv_blocks": 32,
+        "min_prefill_bucket": 8, "prefix_cache": True, "chunk_prefill": 0,
+        "spec_draft": 0},
+    "check": {"logprob_err": 0.2, "greedy_gap": 0.2},
+}
+
+
+def expert_checkout(tmp_path):
+    shutil.copytree(BENCH_DIR, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    (tmp_path / "bench" / "configs" / "tiny-moe.json").write_text(
+        json.dumps(TINY_MOE))
+    (tmp_path / "bench" / "references").mkdir()
+    shutil.copy(BENCH_DIR / "reference.py",
+                tmp_path / "bench" / "references" / "tiny-moe.py")
+    bench = json.loads((ROOT / "BENCHMARK.json").read_text())
+    bench["configs"].append({"name": "tiny-moe",
+                             "source": TINY_MOE["source"],
+                             "file": "bench/configs/tiny-moe.json",
+                             "reduced": [], "why": "sparse experts"})
+    bench["workloads"].append({"name": "tiny-moe.rollout",
+                               "config": "tiny-moe", "traffic": "rollout",
+                               "chips": 1, "why": "expert decode"})
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in ("rollout_tok_s", "step_roofline.rollout"):
+            m["workloads"].append("tiny-moe.rollout")
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(bench))
+
+
+def test_new_expert_cell_from_new_files_and_entries(tmp_path):
+    """A later change adds an expert configuration, its own reference and a
+    cell by new files and entries alone: the harness builds and serves it,
+    and the check goes through the reference the file names."""
+    from bench.check import Sequence, check_sample, reference_module
+    expert_checkout(tmp_path)
+    cell = load_cell("tiny-moe.rollout", root=tmp_path)
+    assert {m["name"] for m in cell.end_to_end} == {"rollout_tok_s",
+                                                    "setup_s"}
+    assert [m["name"] for m in cell.per_layer] == ["step_roofline.rollout"]
+    cfg = harness.model_config(cell.config)
+    assert cfg.family == "moe" and not cfg.qk_norm
+    assert (cfg.moe.num_experts, cfg.moe.top_k, cfg.moe.expert_d_ff,
+            cfg.moe.shared_d_ff, cfg.moe.num_shared_experts,
+            cfg.moe.norm_topk_prob) == (4, 2, 128, 128, 1, False)
+    seed = 2 ** 31 + 9
+    cfg, pool = harness.build_pool(cell.config, seed)
+    params = pool.engines[0].params
+    prompts = np.random.default_rng(seed).integers(2, 512, (3, 12))
+    reqs = [pool.submit_request(p.astype(np.int32), max_new_tokens=10,
+                                temperature=0.0) for p in prompts]
+    for _ in range(100):
+        if pool.idle:
+            break
+        pool.step()
+    assert all(r.finished and len(r.completion) == 10 for r in reqs)
+    ref = reference_module(cell.config, tmp_path)
+    assert ref.__file__ == str(tmp_path / "bench" / "references"
+                               / "tiny-moe.py")
+    out = check_sample(cell.config, seed, [Sequence.of_request(r)
+                                           for r in reqs], root=tmp_path)
+    assert out["correct"] is True, out
+    assert out["compared"]["greedy_tokens"] == 30
+    # what the file says and the program does not run is refused
+    bad = dict(cell.config, moe_intermediate_size=64)
+    with pytest.raises(BenchError, match="moe_intermediate_size"):
+        harness.check_config(bad, cfg, params)
+    with pytest.raises(BenchError, match="moe.w_gate of shape"):
+        harness.check_config(bad, harness.model_config(bad), params)
+    with pytest.raises(BenchError, match="no_such_field"):
+        harness.model_config(dict(cell.config,
+                                  program={"no_such_field": True}))
+
+
+@pytest.mark.parametrize("change,named", [
+    ({"hidden_act": "gelu"}, "hidden_act"),
+    ({"decoder_sparse_step": 2}, "decoder_sparse_step"),
+    ({"mlp_only_layers": [0]}, "mlp_only_layers"),
+    # a field that a published key sets is not set again under "program"
+    ({"program": {"d_model": 128}}, "d_model"),
+    ({"program": {"norm_topk_prob": True}}, "norm_topk_prob"),
+    # an expert field in a file without experts
+    ({"num_experts": 0, "program": {"router_noise": 0.1}}, "no experts"),
+    ({"norm_topk_prob": None}, "norm_topk_prob"),        # left out
+])
+def test_expert_files_the_program_cannot_run_are_refused(change, named):
+    config = dict(TINY_MOE, **change)
+    config = {k: v for k, v in config.items()
+              if k not in change or v is not None}
+    with pytest.raises(BenchError, match=named):
+        harness.model_config(config)
+
+
 def test_compile_cache_sits_inside_the_checkout(tmp_path):
     """Whatever the environment named, the cache is the checkout's own, at
     a fixed path, and every program goes into it."""

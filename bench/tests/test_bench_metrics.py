@@ -34,7 +34,9 @@ def record(**over):
     rec = Record(cell="c", t0=10.0, t1=20.0, items=items,
                  steps=[(10.4, 10.6), (10.9, 11.6), (12.9, 13.6)],
                  counters={"prefill_tokens": 900, "prefill_requests": 2,
-                           "extend_requests": 7, "group_fork_requests": 0},
+                           "extend_requests": 7, "group_fork_requests": 0,
+                           "step_s": 0.1, "readback_s": 0.06,
+                           "admit_s": 0.18},
                  occupancy=[16, 16, 8, 0], slots=16, chips=1, peaks=PEAKS,
                  model=MODEL, setup_s=42.5,
                  trace=Summary(window_s=10.0, busy_s=9.0, devices=1,
@@ -48,7 +50,7 @@ def record(**over):
 def expected():
     flops = sum(work.token_flops(MODEL, n)
                 for n in (101, 102, 103, 50, 51, 52, 10, 11))
-    least = sum(work.least_seconds(MODEL, lv, PEAKS)
+    least = sum(work.least_seconds(MODEL, lv, PEAKS, 1)
                 for lv in ([101], [102, 103], [51, 52]))
     return {
         "rollout_tok_s": 8 / 10.0,
@@ -63,6 +65,11 @@ def expected():
         "step_roofline.rollout": 100.0 * least / 10.0,
         "idle_share.rollout": 10.0,
         "idle_share.agent": 10.0,
+        # 0.1 s in step() less 0.06 s of readbacks, over 4 steps
+        "step_host_ms.rollout": 10.0,
+        "step_host_ms.agent": 10.0,
+        # 0.18 s of admission over 2 prefills and 7 extends
+        "admit_ms_per_turn.agent": 20.0,
     }
 
 
@@ -107,7 +114,7 @@ def test_shares_of_peak_stay_under_the_roofline():
     bounds = [10.0]
     for k in range(1, n + 1):
         bounds.append(bounds[-1] + work.least_seconds(MODEL, [ctx + k] * 16,
-                                                      PEAKS))
+                                                      PEAKS, 1))
     steps = list(zip(bounds[:-1], bounds[1:]))
     stamps = [9.0] + [(a + b) / 2 for a, b in steps]
     items = [Item(req(stamps), due=0.0, released=0.0, ctx=ctx)
@@ -116,3 +123,10 @@ def test_shares_of_peak_stay_under_the_roofline():
     assert reader("step_roofline.rollout").compute(rec) == pytest.approx(
         100.0)
     assert reader("step_mfu.rollout").compute(rec) < 100.0
+
+
+@pytest.mark.parametrize("name", ["step_roofline.rollout", "step_mfu.rollout"])
+def test_shares_of_peak_count_the_cells_chips(name):
+    # the same work on four chips is a quarter of their summed peak
+    one = reader(name).compute(record())
+    assert reader(name).compute(record(chips=4)) == pytest.approx(one / 4)

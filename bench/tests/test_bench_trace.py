@@ -37,6 +37,22 @@ def test_reduce_hand_built_events():
     assert [n for n, _ in b["device_ops"]] == ["tick", "fork"]
 
 
+def test_collective_seconds_from_hlo_names():
+    host = [("bench.window", 0.0, 10.0)]
+    ops = [("jit_step:all-gather.3", 0.0, 1.0),
+           ("jit_step:all-reduce-start.1", 1.0, 1.5),
+           ("jit_step:all-reduce-done.1", 2.0, 2.25),
+           ("reduce-scatter", 3.0, 3.5), ("all-to-all.7", 4.0, 4.125),
+           ("jit_step:collective-permute-done.2", 5.0, 5.0625),
+           ("jit_step:fusion.2", 6.0, 8.5), ("copy-start.1", 8.5, 9.0),
+           ("jit_step:all-gather-fusion.1", 9.0, 9.5)]
+    s = trace.reduce_events(host, [ops, ops[:1]])
+    # device 0: 1 + 0.5 + 0.25 + 0.5 + 0.125 + 0.0625; device 1: 1
+    assert s.collective_s == pytest.approx((2.4375 + 1.0) / 2)
+    # the breakdown is as it was: the top operations by name
+    assert s.breakdown()["device_ops"][0][0] == "jit_step:fusion.2"
+
+
 def test_reduce_clips_to_the_window_and_needs_one():
     host = [("bench.window", 2.0, 4.0)]
     s = trace.reduce_events(host, [[("op", 0.0, 3.0), ("op", 3.5, 9.0)]])

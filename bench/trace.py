@@ -13,6 +13,7 @@ from __future__ import annotations
 import bisect
 import glob
 import os
+import re
 import shutil
 import tempfile
 from collections import defaultdict
@@ -23,6 +24,10 @@ WINDOW = "bench.window"
 SPANS = ("pool.step", "release", "drain", "tool_wait")
 MODULES = "XLA Modules"
 TOP = 10
+# an HLO instruction name of a collective, such as ``all-gather.3`` or
+# ``all-reduce-start.1``
+COLLECTIVE = re.compile(r"(all-gather|all-reduce|reduce-scatter|all-to-all"
+                        r"|collective-permute)(-start|-done)?(\.\d+)?$")
 
 
 def tpu_op_lines(plane_name: str, line_name: str) -> bool:
@@ -82,6 +87,14 @@ class Summary:
     @property
     def idle_share(self) -> float:
         return 1.0 - self.busy_s / self.window_s
+
+    @property
+    def collective_s(self) -> float:
+        """Seconds (device mean) of the operations that exchange data
+        between chips: all-gather, all-reduce, reduce-scatter, all-to-all
+        and collective-permute, and the start and done halves of each."""
+        return sum(v for k, v in self.op_seconds.items()
+                   if COLLECTIVE.match(k.rsplit(":", 1)[-1]))
 
     def breakdown(self) -> dict:
         ops = sorted(self.op_seconds.items(), key=lambda kv: -kv[1])[:TOP]
